@@ -3,8 +3,4 @@
 See DESIGN.md (system inventory + paper mapping) and EXPERIMENTS.md
 (validation, dry-run, roofline, perf log)."""
 
-from . import _jax_compat
-
-_jax_compat.install()
-
 __version__ = "1.0.0"

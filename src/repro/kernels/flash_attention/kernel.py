@@ -7,11 +7,14 @@ the causal/window band are skipped with ``pl.when`` — for a 2 K window over
 a 32 K sequence only ~2/32 of the KV blocks are touched, which is where the
 sub-quadratic long-context cost comes from on the TPU target.
 
-BlockSpec tiling: q/out ``(1, BQ, 1, D)``, k/v ``(1, BK, 1, D)`` with the KV
-head picked by ``h // group`` in the index map (GQA without materializing
-repeated heads).  VMEM working set = BQ*D + 2*BK*D + BQ*BK floats — with the
-default BQ=BK=512, D=128 that is ~1.6 MB, comfortably inside the ~16 MB VMEM
-budget and MXU-aligned (multiples of 128 everywhere).
+The wrapper moves heads ahead of sequence (``[B, H, S, D]``) so that every
+block's last two dims are ``(block, D)``, the tiling Mosaic accepts for any
+head count.  BlockSpec tiling: q/out ``(1, 1, BQ, D)``, k/v
+``(1, 1, BK, D)`` with the KV head picked by ``h // group`` in the index
+map (GQA without materializing repeated heads).  VMEM working set =
+BQ*D + 2*BK*D + BQ*BK floats — with the default BQ=BK=512, D=128 that is
+~1.6 MB, comfortably inside the ~16 MB VMEM budget and MXU-aligned
+(multiples of 128 everywhere).
 """
 
 from __future__ import annotations
@@ -52,9 +55,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale     # [BQ, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)             # [BK, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)             # [BK, D]
+        q = q_ref[0, 0].astype(jnp.float32) * scale           # [BQ, D]
+        k = k_ref[0, 0].astype(jnp.float32)                   # [BK, D]
+        v = v_ref[0, 0].astype(jnp.float32)                   # [BK, D]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [BQ, BK]
         if softcap is not None:
@@ -81,7 +84,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
         denom = jnp.maximum(l_ref[:, 0], 1e-20)[:, None]
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -115,19 +118,16 @@ def flash_attention(
         _flash_kernel, scale=scale, causal=causal, window=window,
         softcap=softcap, block_q=block_q, block_k=block_k, num_k_blocks=n_k)
 
-    return pl.pallas_call(
+    q_spec = pl.BlockSpec((1, 1, block_q, D),
+                          lambda b, h, qi, ki: (b, h, qi, 0))
+    kv_spec = pl.BlockSpec((1, 1, block_k, D),
+                           lambda b, h, qi, ki: (b, h // group, ki, 0))
+    out = pl.pallas_call(
         kernel,
         grid=(B, H, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D),
-                         lambda b, h, qi, ki: (b, ki, h // group, 0)),
-            pl.BlockSpec((1, block_k, 1, D),
-                         lambda b, h, qi, ki: (b, ki, h // group, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D),
-                               lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),   # running max
             pltpu.VMEM((block_q, 1), jnp.float32),   # running denom
@@ -137,4 +137,5 @@ def flash_attention(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(q, k, v)
+    )(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+    return out.transpose(0, 2, 1, 3)

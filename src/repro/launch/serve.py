@@ -1,7 +1,9 @@
 """Serving driver: the session client API over continuous batching.
 
   python -m repro.launch.serve --arch qwen2-1.5b --requests 12
-  python -m repro.launch.serve --rate 8 --shared-prefix 0.5   # open loop
+  python -m repro.launch.serve --smoke --rate 8 --shared-prefix 0.5
+
+Without ``--smoke`` it serves the published config at full width.
 
 Each run opens one session per consistency mode named in ``--modes``
 (sessions coexist on ONE engine; only STRICT sessions pay oplog
@@ -28,6 +30,7 @@ from ..models.spec import init_params
 from ..obs import Obs
 from ..serve import ArrivalSpec, OpenLoopDriver, ServeClient, SpecConfig
 from ..serve.arrival import poisson_schedule
+from .jax_setup import device_line, enable_compile_cache
 
 
 def make_prompts(rng, vocab: int, n: int, shared_frac: float) -> list:
@@ -65,6 +68,9 @@ def _print_stragglers(engine) -> None:
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="qwen2-1.5b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="the arch's reduced smoke config instead of its "
+                         "published widths")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=128)
@@ -116,7 +122,9 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    cfg = get_config(args.arch, smoke=True)
+    enable_compile_cache()
+    print(f"[serve] {device_line()}")
+    cfg = get_config(args.arch, smoke=args.smoke)
     api = build_model(cfg)
     params = init_params(api.init_specs(), jax.random.PRNGKey(args.seed))
     modes = [Mode[m.strip().upper()] for m in args.modes.split(",")]

@@ -29,13 +29,16 @@ from ..data import TokenPipeline
 from ..dist.fault import FaultPolicy, HeartbeatMonitor
 from ..models import build_model
 from ..train import AdamWConfig, LoopConfig, run_training
+from .jax_setup import device_line, enable_compile_cache
 from .mesh import make_host_mesh
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="qwen2-1.5b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="the arch's reduced smoke config instead of its "
+                         "published widths")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=128)
@@ -61,6 +64,8 @@ def main() -> None:
                          "Multi-host deployments pass a real timeout.")
     args = ap.parse_args()
 
+    enable_compile_cache()
+    print(f"[train] {device_line()}")
     cfg = get_config(args.arch, smoke=args.smoke)
     api = build_model(cfg)
     mesh = make_host_mesh()

@@ -176,13 +176,17 @@ class ServeClient:
                  make_oplog: Optional[Callable[[], OpLog]] = None,
                  heartbeat_timeout: float = 6.0,
                  tokenizer: Optional[ByteTokenizer] = None,
-                 obs: Optional[Obs] = None) -> None:
+                 obs: Optional[Obs] = None,
+                 step_fn=None) -> None:
         # host_cache_pages > 0 attaches the host-memory cold tier under
         # the device pool (DESIGN.md §8a): evicted prefix chains spill
         # D2H instead of being forgotten, and matching admissions promote
         # them back with an async copy overlapped ahead of prefill.
         # pool_pages caps the device pool below its geometry (pressure
-        # modeling / capacity planning).
+        # modeling / capacity planning).  step_fn replaces the engine's
+        # own ``jax.jit(api.serve_step)`` with a callable of the same
+        # signature (e.g. executables compiled ahead of time); a cluster
+        # builds one jitted step for all of its engines.
         self._default_mode = default_mode
         self.tokenizer = tokenizer if tokenizer is not None \
             else ByteTokenizer()
@@ -194,6 +198,8 @@ class ServeClient:
                 raise ValueError(
                     "cluster mode: pass make_oplog (one log per engine "
                     "volume), not a single shared oplog")
+            if step_fn is not None:
+                raise ValueError("cluster mode builds its own step_fn")
             self.engine = EngineCluster(
                 api, params, n_engines=n_engines, n_spares=n_spares,
                 heartbeat_timeout=heartbeat_timeout, max_batch=max_batch,
@@ -211,7 +217,7 @@ class ServeClient:
                 else (make_oplog() if make_oplog is not None else None),
                 prefix_cache=prefix_cache,
                 host_cache_pages=host_cache_pages, pool_pages=pool_pages,
-                obs=obs)
+                obs=obs, step_fn=step_fn)
         self.obs = obs
         self._sids = itertools.count()
         self.sessions: Dict[int, Session] = {}

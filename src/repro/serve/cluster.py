@@ -9,7 +9,10 @@ design: the cluster is a THIN metadata plane — routing (prefix-affinity
 hash, ``router.PrefixRouter``), liveness (``dist.fault`` heartbeat
 ladder), and migration orchestration — while every token touches only a
 per-engine data plane.  All engines share ONE jitted step function
-(identical shapes => identical executable: N engines, one compile).
+(identical shapes => identical executable: N engines, one compile per
+device).  When the process holds several devices, engine ``i`` lives on
+``jax.devices()[i % n]`` with its own copy of the weights; engines still
+step one after another.
 
 Fault story, reusing the training fault plane verbatim:
 
@@ -81,6 +84,10 @@ class EngineCluster:
         total = n_engines + n_spares
         # one compiled program for the whole fleet
         step_fn = jax.jit(api.serve_step)
+        # a process that holds several chips gives each engine its own:
+        # its own copy of the weights and its own pools (a replica per
+        # chip); with one device every engine shares it
+        devices = jax.devices()
         self.engines: List[ServingEngine] = []
         for eid in range(total):
             eng = ServingEngine(
@@ -90,7 +97,9 @@ class EngineCluster:
                 oplog=make_oplog() if make_oplog is not None else None,
                 prefix_cache=prefix_cache, spec=spec,
                 host_cache_pages=host_cache_pages, pool_pages=pool_pages,
-                obs=Obs() if per_engine_obs else None, step_fn=step_fn)
+                obs=Obs() if per_engine_obs else None, step_fn=step_fn,
+                device=devices[eid % len(devices)] if len(devices) > 1
+                else None)
             eng._rid = itertools.count(eid * _RID_STRIDE)
             self.engines.append(eng)
         self.router = router if router is not None else PrefixRouter(

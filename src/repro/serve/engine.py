@@ -40,7 +40,6 @@ from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from ..core.kvcache import KVPoolFullError, PagedKVCache
@@ -142,9 +141,15 @@ class ServingEngine:
                  host_cache_pages: int = 0,
                  pool_pages: Optional[int] = None,
                  obs: Optional[Obs] = None,
-                 step_fn=None) -> None:
+                 step_fn=None,
+                 device: Optional[jax.Device] = None) -> None:
         self.api = api
-        self.params = params
+        # ``device`` pins this engine to one chip: its weights, pools and
+        # every host upload live there, so the step runs there (a cluster
+        # replica per chip).  None keeps JAX's default placement.
+        self.device = device
+        self.params = params if device is None \
+            else jax.device_put(params, device)
         self.max_batch = max_batch
         self.max_seq = max_seq
         self.page_tokens = page_tokens
@@ -155,7 +160,10 @@ class ServingEngine:
         self.default_sampling = GREEDY if greedy \
             else SamplingParams(temperature=1.0)
         self.rng = np.random.default_rng(seed)
-        self.caches = api.init_caches(max_batch, max_seq, page_tokens)
+        # built on the device itself, then committed to it
+        with jax.default_device(device):
+            self.caches = jax.device_put(
+                api.init_caches(max_batch, max_seq, page_tokens), device)
         geom = api.kv_geometry(max_batch, max_seq, page_tokens)
         if "page_table" in self.caches:
             assert tuple(self.caches["page_table"].shape) == \
@@ -503,8 +511,9 @@ class ServingEngine:
         part_reqs = [self.active[slot] for slot in feeds]
         if obs is not None:
             t_stage1 = time.perf_counter_ns()
-        logits, self.caches = self._step_fn(self.params, jnp.asarray(tokens),
-                                            self.caches, jnp.asarray(n_new))
+        logits, self.caches = self._step_fn(
+            self.params, self._upload(tokens), self.caches,
+            self._upload(n_new))
         if obs is not None:
             # honest device attribution: without the sync the dispatch
             # returns immediately and device time leaks into the host
@@ -964,12 +973,17 @@ class ServingEngine:
         pt = np.zeros_like(ctrl[:self.max_batch])
         for slot, req in self.active.items():
             pt[slot] = ctrl[req.seq_id]
-        self.caches["page_table"] = jnp.asarray(pt)
+        self.caches["page_table"] = self._upload(pt)
 
     def _set_device_length(self, slot: int, value: int) -> None:
         lengths = np.asarray(self.caches["lengths"]).copy()
         lengths[slot] = value
-        self.caches["lengths"] = jnp.asarray(lengths)
+        self.caches["lengths"] = self._upload(lengths)
+
+    def _upload(self, x: np.ndarray) -> jax.Array:
+        """H2D copy onto this engine's device (the default one when
+        unpinned)."""
+        return jax.device_put(x, self.device)
 
     def _walk_state(self, fn) -> None:
         """Apply ``fn(leaf, batch_dim) -> leaf`` to every recurrent/SSM
@@ -1035,7 +1049,7 @@ class ServingEngine:
 
         def put(leaf, batch_dim):
             idx = (slice(None),) * batch_dim + (slot,)
-            return leaf.at[idx].set(jnp.asarray(next(it)))
+            return leaf.at[idx].set(self._upload(next(it)))
 
         self._walk_state(put)
 

@@ -481,3 +481,88 @@ def test_session_text_prompt_equals_token_path(qwen):
     solo = ServeClient(api, params, max_batch=2, max_seq=64, page_tokens=8)
     out_ids = list(solo.open_session().generate(ids, max_new_tokens=6))
     assert out_text == out_ids and len(out_text) == 6
+
+
+# -------------------------------------------------- one engine per device
+
+_PLACED_CLUSTER_SCRIPT = r"""
+import json
+import jax
+from repro.configs import get_config
+from repro.models import build_model
+from repro.models.spec import init_params
+from repro.serve import EngineCluster
+import numpy as np
+
+cfg = get_config("qwen2-1.5b", smoke=True)
+api = build_model(cfg)
+params = init_params(api.init_specs(), jax.random.PRNGKey(0))
+rng = np.random.default_rng(7)
+heads = [list(rng.integers(1, cfg.vocab, 16)) for _ in range(2)]
+prompts = [heads[i % 2] + list(rng.integers(1, cfg.vocab, 6 + i % 5))
+           for i in range(6)]
+
+def placed(eng):
+    return sorted({str(d) for x in jax.tree.leaves((eng.params, eng.caches))
+                   for d in x.devices()})
+
+def run(kill):
+    cluster = EngineCluster(api, params, n_engines=2, n_spares=1,
+                            max_batch=2, max_seq=64, page_tokens=8,
+                            heartbeat_timeout=3.0)
+    reqs = [cluster.submit(p, max_new_tokens=12) for p in prompts]
+    if kill:
+        for _ in range(3):
+            cluster.step()
+        cluster.kill(max(range(2), key=lambda e: len(cluster.engines[e].active)))
+    cluster.run_until_done(max_steps=600)
+    return cluster, [r.output for r in reqs]
+
+_, clean = run(False)
+cluster, faulted = run(True)
+print("RESULT " + json.dumps({
+    "devices": [str(e.device) for e in cluster.engines],
+    "placed": [placed(e) for e in cluster.engines],
+    "migrated": cluster.sessions_migrated,
+    "same": clean == faulted}))
+"""
+
+
+def test_cluster_places_each_engine_on_its_own_device():
+    """With several devices in the process, each engine holds its own copy
+    of the weights and its own pools on ``jax.devices()[i]``, and a kill
+    migrates sessions across devices with token-identical outputs.  Runs on
+    three virtual CPU devices in a subprocess: the device count is fixed
+    when jax starts."""
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=3").strip()
+    env["PYTHONPATH"] = str(Path(__file__).resolve().parents[1] / "src") + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    proc = subprocess.run([sys.executable, "-c", _PLACED_CLUSTER_SCRIPT],
+                          capture_output=True, text=True, env=env,
+                          timeout=600)
+    assert proc.returncode == 0, f"subprocess failed:\n{proc.stderr[-4000:]}"
+    line = [ln for ln in proc.stdout.splitlines()
+            if ln.startswith("RESULT ")][-1]
+    out = json.loads(line[len("RESULT "):])
+    assert len(set(out["devices"])) == 3
+    assert out["placed"] == [[d] for d in out["devices"]]
+    assert out["migrated"] >= 1
+    assert out["same"]
+
+
+def test_single_device_cluster_keeps_default_placement(qwen):
+    cfg, api, params = qwen
+    cluster = EngineCluster(api, params, n_engines=2, n_spares=1,
+                            max_batch=2, max_seq=64, page_tokens=8)
+    assert len(jax.devices()) == 1
+    assert all(e.device is None for e in cluster.engines)
+    assert all(e.params is params for e in cluster.engines)

@@ -218,7 +218,6 @@ _MULTIPOD_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    import repro  # noqa: F401  (installs jax 0.4.x shims)
     from repro.dist import compression
     from repro.dist.compression import (
         BLOCK, bucketed_compressed_psum, init_residuals, plan_buckets)

@@ -146,3 +146,40 @@ def test_dryrun_smoke_respects_skip_table():
         pytest.skip("arch runs long_500k; skip rule not applicable")
     with pytest.raises(ValueError):
         lower_cell("qwen2-1.5b", "long_500k", smoke=True)
+
+
+# ---------------------------------------------------------------- jax set-up
+
+
+def test_compile_cache_leaves_env_dir_to_jax(monkeypatch):
+    from repro.launch.jax_setup import enable_compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/cache/from/env")
+    assert enable_compile_cache() == "/cache/from/env"
+    assert jax.config.jax_compilation_cache_dir == was
+
+
+def test_compile_cache_defaults_to_fixed_checkout_dir(monkeypatch):
+    from pathlib import Path
+
+    from repro.launch.jax_setup import enable_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        path = enable_compile_cache()
+        assert path == str(Path(__file__).resolve().parents[1] / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert enable_compile_cache() == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_device_line_names_platform_kind_and_count():
+    from repro.launch.jax_setup import device_line
+
+    dev = jax.devices()[0]
+    assert device_line() == (f"platform={dev.platform} "
+                             f"device_kind={dev.device_kind} "
+                             f"count={len(jax.devices())}")
