@@ -62,6 +62,7 @@ def kv_append_chunk(
     return pl.pallas_call(
         _append_kernel,
         grid_spec=grid_spec,
+        name="kv_append_chunk",
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={3: 0},
         compiler_params=pltpu.CompilerParams(

@@ -141,6 +141,7 @@ def paged_attention_chunk(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="paged_attention_chunk",
         out_shape=jax.ShapeDtypeStruct((B, KV, CG, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),

@@ -174,6 +174,7 @@ def lm_init_caches(cfg: ModelConfig, batch: int, max_seq: int,
     return caches
 
 
+@jax.named_scope("serve_step")
 def lm_serve_step(params: Dict, cfg: ModelConfig, tokens: jnp.ndarray,
                   caches: Dict, n_new: jnp.ndarray) -> Tuple[jnp.ndarray, Dict]:
     """Unified chunked serve step (prefill chunks AND decode in one

@@ -1,19 +1,23 @@
 """repro.obs — low-overhead observability for the serving path
-(DESIGN.md §10): span tracing to Chrome trace-event JSON, a counter/
-gauge registry over the stack's existing plain-int stats, an SPFS-style
-windowed profiler, and the SplitFS software-overhead ledger (client /
-scheduler / device / persistence attribution).
+(DESIGN.md §10): the engine's step phases as profiler annotations (kept
+in memory while a profiler session is active) and as Chrome trace-event
+JSON, a counter/gauge registry over the stack's existing plain-int
+stats, an SPFS-style windowed profiler, and the SplitFS
+software-overhead ledger (client / scheduler / device / persistence
+attribution).
 
-Everything hangs off one optional ``Obs`` bundle; ``obs=None`` keeps
-the hot path untouched."""
+The annotations are always entered; everything else hangs off one
+optional ``Obs`` bundle."""
 
 from .bundle import Obs, OverheadLedger, attach_fault, attach_serving
 from .profiler import Window, WindowedProfiler
 from .registry import Counter, Gauge, Registry
-from .trace import SpanTracer, validate_chrome_trace
+from .trace import (SpanTracer, interval, program_tracer, span,
+                    validate_chrome_trace)
 
 __all__ = [
     "Obs", "OverheadLedger", "attach_fault", "attach_serving",
     "Window", "WindowedProfiler", "Counter", "Gauge", "Registry",
-    "SpanTracer", "validate_chrome_trace",
+    "SpanTracer", "interval", "program_tracer", "span",
+    "validate_chrome_trace",
 ]

@@ -2,17 +2,17 @@
 optional span tracer, handed to the serving stack as ONE optional object.
 
 ``obs=None`` (the default everywhere) is the instrumentation-off mode:
-the hot path pays only ``if obs is not None`` branches — no clock reads,
-no allocations, no trace entries (the <2% bound of DESIGN.md §10 holds
-by construction; the CI smoke cell measures even the *enabled* cost
-against it).
+the engine runs the same step, its ``serve.*`` spans record nothing
+without a profiler session, and no ledger is kept (DESIGN.md §10; the
+CI smoke cell measures even the *enabled* cost against the <2% bound).
 
 The overhead ledger is the SplitFS software-overhead decomposition
-applied to serving: each engine step's wall time is split into
+applied to serving: each engine step's ``serve.step`` span is split into
 
     scheduler   host control-plane time (admission, staging metadata,
                 backpressure, sampling, device-mirror sync)
-    device      the jitted ``serve_step`` to ``block_until_ready``
+    device      ``serve.dispatch`` + ``serve.device_wait``: the jitted
+                ``serve_step`` to ``block_until_ready``
     persistence oplog publish time (64 B entry + fence, STRICT only)
 
 keyed by phase (``prefill`` while any batched request is still

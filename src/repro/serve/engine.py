@@ -48,6 +48,7 @@ from ..core.oplog import OpLog
 from ..core.tier import HostTier
 from ..models.registry import ModelAPI
 from ..obs import Obs, attach_serving
+from ..obs.trace import interval, span
 from .prefix_cache import PrefixCache, _Node
 
 
@@ -117,12 +118,13 @@ class Request:
     truncated: bool = False              # finished early (pool backpressure)
     stalled: bool = False                # run_until_done hit max_steps first
     cancelled: bool = False              # aborted by the caller
-    # obs-only fields (None/0 when the engine runs uninstrumented): raw
-    # perf_counter_ns stamps plus the per-request overhead ledger.  Shared
-    # batch time is attributed by even split across the step's
-    # participants, so request ledgers sum to the engine's phase totals.
+    # raw perf_counter_ns stamps: submit, and slot assignment (the
+    # serve.queued interval between them)
     t_submit_ns: int = 0
     t_admit_ns: int = 0
+    # obs-only per-request overhead ledger (None when uninstrumented).
+    # Shared batch time is attributed by even split across the step's
+    # participants, so request ledgers sum to the engine's phase totals.
     ledger: Optional[Dict[str, int]] = None
 
     @property
@@ -239,6 +241,7 @@ class ServingEngine:
         self.promote_events = 0
         self.promote_lag_ns = 0
         self.obs = obs
+        self._tracer = obs.tracer if obs is not None else None
         if obs is not None:
             attach_serving(obs, self)
             if self.tier is not None:
@@ -278,12 +281,11 @@ class ServingEngine:
         req = Request(next(self._rid), list(prompt), max_new_tokens,
                       mode=self.controller.mode if mode is None else mode,
                       sampling=samp, spec=eff_spec)
-        if self.obs is not None:
-            req.t_submit_ns = time.perf_counter_ns()
-            if self.obs.tracer is not None:
-                self.obs.tracer.instant(
-                    "submit", "serve",
-                    args={"rid": req.rid, "prompt": len(req.prompt)})
+        req.t_submit_ns = time.perf_counter_ns()
+        if self._tracer is not None:
+            self._tracer.instant(
+                "submit", "serve",
+                args={"rid": req.rid, "prompt": len(req.prompt)})
         self.waiting.append(req)
         return req
 
@@ -303,12 +305,19 @@ class ServingEngine:
 
     # ------------------------------------------------------------------ engine step
 
+    def _span(self, name: str, **args: int) -> span:
+        """A ``serve.*`` phase of the current step (DESIGN.md §10)."""
+        return span(name, self._tracer, step=self.steps, **args)
+
     def _admit(self) -> None:
         free_slots = [s for s in range(self.max_batch) if s not in self.active]
         while self.waiting and free_slots:
             slot = free_slots.pop(0)
             req = self.waiting.pop(0)
             req.slot = slot
+            req.t_admit_ns = time.perf_counter_ns()
+            interval("serve.queued", req.t_submit_ns, req.t_admit_ns,
+                     self._tracer, step=self.steps, rid=req.rid)
             req.seq_id = self.controller.create_seq(mode=req.mode)
             # prefix-cache attach: adopt the longest published page chain
             # matching the prompt (refcounted hard links) — those tokens'
@@ -316,8 +325,6 @@ class ServingEngine:
             # starts past them so the first real chunk lands after the
             # shared span
             start = 0
-            obs = self.obs
-            tracer = obs.tracer if obs is not None else None
             if self.prefix_cache is not None and req.in_prefill:
                 links, n_tok = self.prefix_cache.match_links(
                     req.prompt, align=self.chunk)
@@ -325,13 +332,8 @@ class ServingEngine:
                 n_host = sum(1 for nd in links if nd.on_host)
                 if n_tok and not n_host:
                     pages = [nd.page for nd in links]
-                    if tracer is not None:
-                        with tracer.span("adopt_prefix", "serve",
-                                         args={"rid": req.rid,
-                                               "pages": len(pages),
-                                               "tokens": n_tok}):
-                            self.controller.adopt_prefix(req.seq_id, pages)
-                    else:
+                    with self._span("serve.adopt_prefix", rid=req.rid,
+                                    pages=len(pages), tokens=n_tok):
                         self.controller.adopt_prefix(req.seq_id, pages)
                     req.prompt_pos = req.prefix_tokens = start = n_tok
                 elif n_tok:
@@ -356,12 +358,11 @@ class ServingEngine:
                          "t_enq": t_enq})
             self._set_device_length(slot, start)
             self._zero_slot_state(slot)
-            if obs is not None:
+            if self.obs is not None:
                 # per-request overhead ledger: client/API time is the queue
                 # wait from submit to admission; scheduler/device/persistence
                 # accrue per step, split evenly across the step's batch so
                 # request ledgers sum to the engine's phase totals
-                req.t_admit_ns = time.perf_counter_ns()
                 req.ledger = {
                     "client_ns": req.t_admit_ns - req.t_submit_ns,
                     "scheduler_ns": 0, "device_ns": 0, "persistence_ns": 0,
@@ -369,31 +370,71 @@ class ServingEngine:
             self.active[slot] = req
 
     def step(self) -> None:
-        obs = self.obs
-        tracer = obs.tracer if obs is not None else None
-        if obs is not None:
-            t_step0 = time.perf_counter_ns()
-            persist0 = self.controller.persist_ns
-        self._admit()
-        if obs is not None:
-            t_admit1 = time.perf_counter_ns()
-        if not self.active:
-            return
-        B = self.max_batch
-        # decode-only batches run the WIDTH-1 slice of the same jitted
-        # step (jax caches one executable per shape: one prefill program,
-        # one decode program — still never retraced), so steady-state
-        # decode never pays the C-wide compute for 1 valid token
-        prefill_any = any(r.in_prefill for r in self.active.values())
-        # drafting pass (host-side prompt lookup) runs BEFORE the width
-        # choice: speculative tokens ride the same chunk lane prefill
-        # uses, so a step with drafts runs the C-wide program.  Draft
-        # time lands in the CLIENT bucket of the overhead split — it is
-        # guesswork spent on the model's behalf, not engine scheduling.
+        """One engine step.  Each phase is one ``serve.*`` span; the
+        overhead ledger reads its split from their stamps."""
+        persist0 = self.controller.persist_ns
+        with self._span("serve.step") as st:
+            with self._span("serve.admit"):
+                self._admit()
+            if not self.active:
+                return
+            # decode-only batches run the WIDTH-1 slice of the same jitted
+            # step (jax caches one executable per shape: one prefill
+            # program, one decode program — still never retraced), so
+            # steady-state decode never pays the C-wide compute for 1
+            # valid token
+            prefill_any = any(r.in_prefill for r in self.active.values())
+            with self._span("serve.stage"):
+                # drafting runs BEFORE the width choice: speculative tokens
+                # ride the same chunk lane prefill uses, so a step with
+                # drafts runs the C-wide program
+                drafts, draft_ns = self._draft_all()
+                C = self.chunk if (prefill_any or drafts) else 1
+                tokens, n_new, feeds, spec_feeds = self._stage(C, drafts)
+            if not feeds:
+                # nothing to compute this step, but staged promotions must
+                # still land (their adopters are the only work left)
+                self._flip_promotions(overlapped=False)
+                return
+            st.args["width"] = C
+            with self._span("serve.sync_page_table"):
+                self._sync_page_table()
+            # keep the participants: finished requests leave ``active`` in
+            # the post loop, but the step's shared cost is still theirs
+            part_reqs = [self.active[slot] for slot in feeds]
+            with self._span("serve.dispatch") as dispatch:
+                logits, self.caches = self._step_fn(
+                    self.params, self._upload(tokens), self.caches,
+                    self._upload(n_new))
+            # staged promotions land HERE — after the step's compute was
+            # dispatched, against the post-step pool arrays (disjoint
+            # pages), so the H2D copies ride the async queue concurrent
+            # with the step instead of serializing ahead of the prefill
+            # that needs them; dataflow ordering guarantees the NEXT step
+            # reads the copied bytes
+            self._flip_promotions(overlapped=True)
+            with self._span("serve.device_wait") as wait:
+                jax.block_until_ready(logits)
+            with self._span("serve.logits_to_host", bytes=logits.nbytes):
+                logits = np.asarray(logits)
+            self.tokens_processed += int(sum(feeds.values()))
+            with self._span("serve.sample"):
+                self._sample_feeds(feeds, spec_feeds, logits)
+            self.steps += 1
+        if self.obs is not None:
+            self._account_step(self.obs, st, dispatch.ns + wait.ns, draft_ns,
+                               self.controller.persist_ns - persist0,
+                               part_reqs,
+                               "prefill" if prefill_any else "decode")
+
+    def _draft_all(self) -> Tuple[Dict[int, List[int]], int]:
+        """Host-side prompt lookup for every speculating slot: the drafts
+        and the ns spent, which the overhead split charges to CLIENT time
+        (guesswork spent on the model's behalf, not engine scheduling)."""
         drafts: Dict[int, List[int]] = {}
-        draft_ns = 0
-        if any(r.spec is not None for r in self.active.values()):
-            t_draft0 = time.perf_counter_ns()
+        if not any(r.spec is not None for r in self.active.values()):
+            return drafts, 0
+        with self._span("serve.draft") as dr:
             for slot, req in self.active.items():
                 sp = req.spec
                 if sp is None or req.in_prefill or not req.output:
@@ -408,18 +449,19 @@ class ServingEngine:
                     d = self._draft(req, k)
                     if d:
                         drafts[slot] = d
-            t_draft1 = time.perf_counter_ns()
-            draft_ns = t_draft1 - t_draft0
-            self.draft_ns += draft_ns
-            if tracer is not None:
-                tracer.complete(
-                    "draft", "serve", tracer.rel(t_draft0),
-                    tracer.rel(t_draft1),
-                    args={"slots": len(drafts),
-                          "tokens": sum(map(len, drafts.values()))})
-        C = self.chunk if (prefill_any or drafts) else 1
-        tokens = np.zeros((B, C), np.int32)
-        n_new = np.zeros((B,), np.int32)
+            dr.args.update(slots=len(drafts),
+                           tokens=sum(map(len, drafts.values())))
+        self.draft_ns += dr.ns
+        return drafts, dr.ns
+
+    def _stage(self, C: int, drafts: Dict[int, List[int]],
+               ) -> Tuple[np.ndarray, np.ndarray, Dict[int, int],
+                          Dict[int, List[int]]]:
+        """The feed loop: pick each slot's tokens, make room, and stage
+        them in the controller.  Returns the step's ``tokens[B, C]``,
+        ``n_new[B]``, the fed count per slot, and the drafts fed."""
+        tokens = np.zeros((self.max_batch, C), np.int32)
+        n_new = np.zeros((self.max_batch,), np.int32)
         feeds: Dict[int, int] = {}
         spec_feeds: Dict[int, List[int]] = {}    # slot -> drafts actually fed
         for slot, req in list(self.active.items()):
@@ -499,39 +541,13 @@ class ServingEngine:
             # an unverified extent (DESIGN.md §8)
             self.controller.append_tokens(req.seq_id, take, reserve=C,
                                           publish=slot not in spec_feeds)
-        if not feeds:
-            # nothing to compute this step, but staged promotions must
-            # still land (their adopters are the only work left)
-            self._flip_promotions(tracer, overlapped=False)
-            return
+        return tokens, n_new, feeds, spec_feeds
 
-        self._sync_page_table()
-        # keep the participants: finished requests leave ``active`` in the
-        # post loop, but the step's shared cost is still theirs to carry
-        part_reqs = [self.active[slot] for slot in feeds]
-        if obs is not None:
-            t_stage1 = time.perf_counter_ns()
-        logits, self.caches = self._step_fn(
-            self.params, self._upload(tokens), self.caches,
-            self._upload(n_new))
-        if obs is not None:
-            # honest device attribution: without the sync the dispatch
-            # returns immediately and device time leaks into the host
-            # sampler below (np.asarray forces the same sync anyway, so
-            # semantics are unchanged)
-            jax.block_until_ready(logits)
-            t_dev1 = time.perf_counter_ns()
-        # staged promotions land HERE — after the step's compute was
-        # dispatched, against the post-step pool arrays (disjoint pages),
-        # so the H2D copies ride the async queue concurrent with the
-        # host-side sampling below instead of serializing ahead of the
-        # prefill that needs them; dataflow ordering guarantees the NEXT
-        # step reads the copied bytes
-        self._flip_promotions(tracer, overlapped=True)
-        logits = np.asarray(logits)
-        self.steps += 1
-        self.tokens_processed += int(sum(feeds.values()))
-
+    def _sample_feeds(self, feeds: Dict[int, int],
+                      spec_feeds: Dict[int, List[int]],
+                      logits: np.ndarray) -> None:
+        """The post-step loop: advance cursors, publish finished prompts
+        into the trie, sample (or verify drafts), and finish requests."""
         for slot, take in feeds.items():
             req = self.active[slot]
             if req.in_prefill:
@@ -543,13 +559,7 @@ class ServingEngine:
                     # the trie so later prompts sharing the prefix adopt
                     # them (idempotent for the pages this request itself
                     # adopted at admission)
-                    if tracer is not None:
-                        with tracer.span("publish", "serve",
-                                         args={"rid": req.rid}):
-                            self.prefix_cache.insert(
-                                req.prompt,
-                                self.controller.committed_extents(req.seq_id))
-                    else:
+                    with self._span("serve.publish", rid=req.rid):
                         self.prefix_cache.insert(
                             req.prompt,
                             self.controller.committed_extents(req.seq_id))
@@ -557,8 +567,7 @@ class ServingEngine:
                 # draft-and-verify epilogue: all take logits came back
                 # from ONE step; accept the longest agreeing prefix and
                 # roll back the rejected tail (metadata-only)
-                self._verify_spec(slot, req, take, spec_feeds[slot],
-                                  logits, tracer)
+                self._verify_spec(slot, req, take, spec_feeds[slot], logits)
             else:
                 # the chunk's last valid position predicts the next
                 # token: the final prefill chunk yields the first
@@ -572,14 +581,8 @@ class ServingEngine:
                 req.truncated = True        # capacity-bound, not completed
                 self._finish(slot, req)
 
-        if obs is not None:
-            self._account_step(obs, tracer, part_reqs, len(feeds),
-                               t_step0, t_admit1, t_stage1, t_dev1,
-                               persist0, draft_ns,
-                               "prefill" if prefill_any else "decode")
-
     def _verify_spec(self, slot: int, req: Request, take: int,
-                     d: List[int], logits: np.ndarray, tracer) -> None:
+                     d: List[int], logits: np.ndarray) -> None:
         """Accept the longest draft prefix the target model agrees with.
 
         The step fed ``[output[-1]] + d`` (take = 1 + len(d) positions),
@@ -597,15 +600,16 @@ class ServingEngine:
         at ANY point replays to exactly the accepted extent.  Rollback
         also CoWs a kept-but-shared tail page; the engine applies the
         device-side copy here."""
-        if tracer is not None:
-            t_v0 = time.perf_counter_ns()
-        new_toks: List[int] = []
-        for i in range(take):
-            tok = self._sample(logits[slot, i], req.sampling)
-            new_toks.append(tok)
-            if i < take - 1 and d[i] != tok:
-                break
-        accepted = len(new_toks) - 1          # drafts the model agreed with
+        with self._span("serve.verify", rid=req.rid,
+                        drafted=len(d)) as sv:
+            new_toks: List[int] = []
+            for i in range(take):
+                tok = self._sample(logits[slot, i], req.sampling)
+                new_toks.append(tok)
+                if i < take - 1 and d[i] != tok:
+                    break
+            accepted = len(new_toks) - 1      # drafts the model agreed with
+            sv.args["accepted"] = accepted
         emit = new_toks[:req.max_new_tokens - len(req.output)]
         req.output.extend(emit)
         req.spec_drafted += len(d)
@@ -614,12 +618,6 @@ class ServingEngine:
         self.spec_drafted_tokens += len(d)
         self.spec_accepted_tokens += accepted
         self.spec_rejected_tokens += len(d) - accepted
-        if tracer is not None:
-            t_v1 = time.perf_counter_ns()
-            tracer.complete("verify", "serve", tracer.rel(t_v0),
-                            tracer.rel(t_v1),
-                            args={"rid": req.rid, "drafted": len(d),
-                                  "accepted": accepted})
         # the KV invariant (prompt + output[:-1] staged) pins the target:
         # the last emitted token is NEXT step's feed, so its KV position
         # does not exist yet — exactly like normal decode
@@ -627,34 +625,27 @@ class ServingEngine:
         target = (total_after - take) + len(emit)
         if target < total_after:
             self.spec_rollbacks += 1
-        self.controller.commit(req.seq_id, upto_len=target)
-        cowed = self._rollback_to(req, target)
-        if tracer is not None:
-            tracer.complete("rollback", "serve", tracer.rel(t_v1),
-                            tracer.now_ns(),
-                            args={"rid": req.rid,
-                                  "rejected": total_after - target,
-                                  "cow": cowed})
+        with self._span("serve.rollback", rid=req.rid,
+                        rejected=total_after - target) as rb:
+            self.controller.commit(req.seq_id, upto_len=target)
+            rb.args["cow"] = int(self._rollback_to(req, target))
 
-    def _account_step(self, obs: Obs, tracer, part_reqs: List[Request],
-                      n_part: int, t_step0: int, t_admit1: int,
-                      t_stage1: int, t_dev1: int, persist0: int,
-                      draft_ns: int, phase: str) -> None:
-        """Obs-only epilogue: split the step's wall time into scheduler /
-        device / persistence (SplitFS-style attribution, DESIGN.md §10),
-        charge the phase ledger and each participant's request ledger, emit
-        the step's span family, and tick the windowed profiler.  Drafting
-        time is CLIENT time (guesswork outside the engine's control
-        plane), subtracted from the scheduler bucket."""
-        t_end = time.perf_counter_ns()
-        persist_ns = self.controller.persist_ns - persist0
-        device_ns = t_dev1 - t_stage1
-        sched_ns = max((t_end - t_step0) - device_ns - persist_ns
-                       - draft_ns, 0)
+    def _account_step(self, obs: Obs, st: span, device_ns: int,
+                      draft_ns: int, persist_ns: int,
+                      part_reqs: List[Request], phase: str) -> None:
+        """Obs-only epilogue: split the ``serve.step`` span into scheduler
+        / device / persistence (SplitFS-style attribution, DESIGN.md §10)
+        — device is ``serve.dispatch`` + ``serve.device_wait`` — charge
+        the phase ledger and each participant's request ledger, and tick
+        the windowed profiler.  Drafting time is CLIENT time (guesswork
+        outside the engine's control plane), subtracted from the
+        scheduler bucket."""
+        sched_ns = max(st.ns - device_ns - persist_ns - draft_ns, 0)
         obs.ledger.add(phase, sched_ns=sched_ns, device_ns=device_ns,
                        persist_ns=persist_ns, steps=1)
         if draft_ns:
             obs.ledger.add_client(draft_ns)
+        n_part = len(part_reqs)
         for req in part_reqs:
             led = req.ledger
             if led is not None:
@@ -663,16 +654,6 @@ class ServingEngine:
                 led["persistence_ns"] += persist_ns // n_part
                 led["client_ns"] += draft_ns // n_part
                 led["steps"] += 1
-        if tracer is not None:
-            rel = tracer.rel
-            tracer.complete("step", "serve", rel(t_step0), rel(t_end),
-                            args={"phase": phase, "slots": n_part,
-                                  "persist_us": persist_ns / 1e3})
-            tracer.complete("admit", "serve", rel(t_step0), rel(t_admit1))
-            tracer.complete("schedule", "serve", rel(t_admit1), rel(t_stage1))
-            tracer.complete("serve_step", "device", rel(t_stage1),
-                            rel(t_dev1))
-            tracer.complete("sample", "serve", rel(t_dev1), rel(t_end))
         obs.profiler.observe()
 
     def cancel(self, req: Request) -> None:
@@ -684,9 +665,8 @@ class ServingEngine:
             return
         req.cancelled = True
         self.cancels += 1
-        if self.obs is not None and self.obs.tracer is not None:
-            self.obs.tracer.instant("cancel", "serve",
-                                    args={"rid": req.rid})
+        if self._tracer is not None:
+            self._tracer.instant("cancel", "serve", args={"rid": req.rid})
         if req in self.waiting:
             self.waiting.remove(req)
             req.done = True
@@ -722,10 +702,9 @@ class ServingEngine:
         self.finished.append(req)
         self.controller.free_seq(req.seq_id)
         del self.active[slot]
-        obs = self.obs
-        if obs is not None and obs.tracer is not None and req.ledger:
+        tracer = self._tracer
+        if tracer is not None and req.ledger:
             # one request-lifetime span per slot lane, ledger in the args
-            tracer = obs.tracer
             tracer.complete(
                 f"req{req.rid}", "request", tracer.rel(req.t_admit_ns),
                 tracer.now_ns(), tid=100 + slot,
@@ -828,7 +807,7 @@ class ServingEngine:
             keep -= 1
         return links[:keep], keep * pt
 
-    def _flip_promotions(self, tracer, *, overlapped: bool) -> None:
+    def _flip_promotions(self, *, overlapped: bool) -> None:
         """Land every staged promotion: enqueue the H2D copies (async),
         then flip — controller publish (``finish_adopt``: commit + oplog
         under the adopter's mode), trie re-pin (``promote_commit``), and
@@ -842,37 +821,42 @@ class ServingEngine:
         if not self._promotions:
             return
         pending, self._promotions = self._promotions, []
-        for pr in pending:
-            req: Request = pr["req"]
-            if req.done:
-                # cancelled mid-promotion: free_seq already released the
-                # reserved pages; the chain stays host-resident
-                continue
-            for node, dst, slot in pr["plan"]:
-                if node.on_host and node.host_slot == slot:
-                    self.tier.promote(slot, dst)
-                else:
-                    self._copy_page_on_device(node.page, dst)
-            self.controller.finish_adopt(req.seq_id)
-            for node, dst, slot in pr["plan"]:
-                self.prefix_cache.promote_commit(node, dst, slot)
-            self._set_device_length(req.slot, pr["tokens"])
-            req.promoting = False
-            t1 = time.perf_counter_ns()
-            lag = t1 - pr["t_enq"]
-            self.promote_events += 1
-            self.promote_lag_ns += lag
-            if tracer is not None:
-                # own lane per slot (200+): the [enqueue -> flip] interval
-                # deliberately OVERLAPS the engine lane's serve_step span —
-                # that overlap is the proof the copy ran concurrent with
-                # compute, so it must not share tid 0 (nesting validator)
-                tracer.complete(
-                    "promote", "tier", tracer.rel(pr["t_enq"]),
-                    tracer.rel(t1), tid=200 + req.slot,
-                    args={"rid": req.rid, "pages": len(pr["plan"]),
-                          "tokens": pr["tokens"], "lag_us": lag / 1e3,
-                          "overlapped": overlapped})
+        with self._span("serve.promote_flip"):
+            for pr in pending:
+                self._flip_one(pr, overlapped)
+
+    def _flip_one(self, pr: dict, overlapped: bool) -> None:
+        req: Request = pr["req"]
+        if req.done:
+            # cancelled mid-promotion: free_seq already released the
+            # reserved pages; the chain stays host-resident
+            return
+        for node, dst, slot in pr["plan"]:
+            if node.on_host and node.host_slot == slot:
+                self.tier.promote(slot, dst)
+            else:
+                self._copy_page_on_device(node.page, dst)
+        self.controller.finish_adopt(req.seq_id)
+        for node, dst, slot in pr["plan"]:
+            self.prefix_cache.promote_commit(node, dst, slot)
+        self._set_device_length(req.slot, pr["tokens"])
+        req.promoting = False
+        t1 = time.perf_counter_ns()
+        lag = t1 - pr["t_enq"]
+        self.promote_events += 1
+        self.promote_lag_ns += lag
+        tracer = self._tracer
+        if tracer is not None:
+            # own lane per slot (200+): the [enqueue -> flip] interval
+            # deliberately OVERLAPS the engine lane's serve.dispatch span
+            # — that overlap is the proof the copy ran concurrent with
+            # compute, so it must not share tid 0 (nesting validator)
+            tracer.complete(
+                "promote", "tier", tracer.rel(pr["t_enq"]),
+                tracer.rel(t1), tid=200 + req.slot,
+                args={"rid": req.rid, "pages": len(pr["plan"]),
+                      "tokens": pr["tokens"], "lag_us": lag / 1e3,
+                      "overlapped": overlapped})
 
     def _pool_leaves(self) -> List:
         """The layer page pools in a deterministic walk order — that order

@@ -4,7 +4,11 @@ adoption agreement, Chrome-trace validity + span nesting, the windowed
 profiler, the overhead ledger, fault-plane counters, and the
 disabled-by-default zero-cost guarantee."""
 
+import gc
+import glob
 import json
+import time
+import weakref
 
 import jax
 import pytest
@@ -15,7 +19,7 @@ from repro.dist.fault import FaultPolicy, HeartbeatMonitor
 from repro.models import build_model
 from repro.models.spec import init_params
 from repro.obs import (Obs, OverheadLedger, Registry, SpanTracer,
-                       WindowedProfiler, attach_fault,
+                       WindowedProfiler, attach_fault, program_tracer,
                        validate_chrome_trace)
 from repro.serve import PrefixCache, ServeClient
 
@@ -156,8 +160,11 @@ def test_trace_is_valid_chrome_and_spans_nest(qwen, tmp_path):
     doc = json.loads(path.read_text())
     assert validate_chrome_trace(doc) == []
     names = {ev["name"] for ev in doc["traceEvents"]}
-    for expected in ("step", "admit", "schedule", "serve_step", "sample",
-                     "submit", f"req{r1.rid}"):
+    for expected in ("serve.step", "serve.admit", "serve.stage",
+                     "serve.sync_page_table", "serve.dispatch",
+                     "serve.device_wait", "serve.logits_to_host",
+                     "serve.sample", "serve.queued", "submit",
+                     f"req{r1.rid}"):
         assert expected in names, expected
     # request lifetimes live on their own slot lanes, with the ledger
     req_evs = [ev for ev in doc["traceEvents"] if ev.get("tid", 0) >= 100]
@@ -192,8 +199,9 @@ def test_tracer_cap_and_validator_catches_overlap():
     tr = SpanTracer(max_events=2)
     tr.complete("a", "t", 0, 10)
     tr.complete("b", "t", 2, 8)
-    tr.complete("c", "t", 20, 30)            # over cap: dropped
+    tr.complete("c", "t", 20, 30)            # over cap: the oldest goes
     assert len(tr) == 2 and tr.dropped == 1
+    assert [sp[0] for sp in tr.spans()] == ["b", "c"]
     assert tr.to_chrome()["otherData"]["dropped_events"] == 1
     bad = {"traceEvents": [
         {"name": "a", "cat": "t", "ph": "X", "ts": 0.0, "dur": 10.0,
@@ -203,6 +211,136 @@ def test_tracer_cap_and_validator_catches_overlap():
     ]}
     assert any("overlaps" in p for p in validate_chrome_trace(bad))
     assert validate_chrome_trace({"traceEvents": []})
+
+
+# ---------------------------------------------------------------- step phases
+
+PHASES = ("serve.dispatch", "serve.device_wait", "serve.logits_to_host",
+          "serve.sample")
+TAXONOMY = {"serve.step", "serve.admit", "serve.adopt_prefix",
+            "serve.stage", "serve.draft", "serve.sync_page_table",
+            "serve.dispatch", "serve.promote_flip", "serve.device_wait",
+            "serve.logits_to_host", "serve.sample", "serve.publish",
+            "serve.verify", "serve.rollback"}
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` inside a profiler session as the benchmark starts it;
+    returns the raw perf_counter_ns bounds of the session."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    t0 = time.perf_counter_ns()
+    try:
+        fn()
+    finally:
+        t1 = time.perf_counter_ns()
+        jax.profiler.stop_trace()
+    return t0, t1
+
+
+def _serve_two(client):
+    sess = client.open_session()
+    sess.submit(list(range(1, 20)), max_new_tokens=3)
+    sess.submit(list(range(1, 12)), max_new_tokens=2)
+    client.run_until_done()
+
+
+def test_step_phases_are_profiler_spans_and_ring_entries(qwen, tmp_path):
+    """Under a profiler session the engine's phases appear in the
+    xplane's host plane under their exact names, nested in serve.step,
+    and the in-memory ring holds the same spans on perf_counter_ns."""
+    from jax.profiler import ProfileData
+
+    cfg, api, params = qwen
+    client = ServeClient(api, params, max_batch=2, max_seq=64, page_tokens=8)
+    lo, hi = _profiled(tmp_path, lambda: _serve_two(client))
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    host = [(e.start_ns, e.end_ns, e.name)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("serve.")]
+    assert {n for _, _, n in host} <= TAXONOMY
+    doc = {"traceEvents": [{"name": n, "ph": "X", "ts": s / 1e3,
+                            "dur": (e - s) / 1e3, "pid": 0, "tid": 0}
+                           for s, e, n in host]}
+    assert validate_chrome_trace(doc) == []
+    steps = [(s, e) for s, e, n in host if n == "serve.step"]
+    computed = 0
+    for s0, s1 in steps:
+        inside = sorted((s, n) for s, e, n in host
+                        if s0 <= s and e <= s1 and n != "serve.step")
+        if not any(n == "serve.dispatch" for _, n in inside):
+            continue
+        computed += 1
+        order = [n for _, n in inside if n in PHASES]
+        assert order == list(PHASES), order
+    assert computed == client.engine.steps
+
+    ring = [sp for sp in program_tracer().spans()
+            if lo <= sp[1] and sp[2] <= hi and sp[0] != "serve.queued"]
+    assert sorted(n for n, *_ in ring) == sorted(n for _, _, n in host)
+    B, V = 2, cfg.vocab
+    width = {a["step"]: a["width"] for n, _, _, a in ring
+             if n == "serve.step" and "width" in a}
+    copies = [a for n, _, _, a in ring if n == "serve.logits_to_host"]
+    assert len(copies) == len(width) == computed
+    for a in copies:
+        per = a["bytes"] // (B * width[a["step"]] * V)
+        assert per in (2, 4) and a["bytes"] == per * B * width[a["step"]] * V
+    queued = [a["rid"] for n, _, t1, a in program_tracer().spans()
+              if n == "serve.queued" and lo <= t1 <= hi]
+    assert sorted(queued) == [0, 1]
+
+
+def test_ring_stays_empty_without_a_session_or_obs(qwen):
+    cfg, api, params = qwen
+    ring = program_tracer()
+    before = (len(ring), ring.dropped)
+    _serve_two(ServeClient(api, params, max_batch=2, max_seq=64,
+                           page_tokens=8))
+    assert (len(ring), ring.dropped) == before
+
+
+def test_ring_holds_no_reference_to_the_engine(qwen, tmp_path):
+    """The benchmark frees the served model before its reference runs:
+    the spans the ring keeps must not hold the engine alive."""
+    cfg, api, params = qwen
+    client = ServeClient(api, params, max_batch=2, max_seq=64, page_tokens=8)
+    lo, hi = _profiled(tmp_path, lambda: _serve_two(client))
+    engine = weakref.ref(client.engine)
+    del client
+    gc.collect()
+    assert engine() is None
+    assert any(lo <= t0 and t1 <= hi for _, t0, t1, _ in
+               program_tracer().spans())
+
+
+def test_ledger_split_comes_from_the_step_spans(qwen):
+    """The overhead ledger reads the serve.* spans' stamps: its device
+    bucket is serve.dispatch + serve.device_wait, and its three buckets
+    sum to the computing steps' serve.step time."""
+    cfg, api, params = qwen
+    obs = Obs(trace=True)
+    client = ServeClient(api, params, max_batch=2, max_seq=64, page_tokens=8,
+                         obs=obs)
+    _serve_two(client)
+    spans = obs.tracer.spans()
+
+    def total(*names):
+        return sum(t1 - t0 for n, t0, t1, _ in spans if n in names)
+
+    led = {c: obs.ledger.phase_totals("prefill")[c]
+           + obs.ledger.phase_totals("decode")[c]
+           for c in ("scheduler", "device", "persistence", "steps")}
+    assert led["device"] == total("serve.dispatch", "serve.device_wait")
+    assert led["steps"] == client.engine.steps
+    stepped = sum(t1 - t0 for n, t0, t1, a in spans
+                  if n == "serve.step" and "width" in a)
+    assert led["scheduler"] + led["device"] + led["persistence"] == stepped
 
 
 # ---------------------------------------------------------------- profiler

@@ -334,8 +334,9 @@ def test_staged_adoption_crash_before_flip_replays_to_prefix(qwen):
 
 def test_promote_span_overlaps_serve_step(qwen):
     """The acceptance criterion for async promotion: the [enqueue -> flip]
-    span on the 200+ lane overlaps a serve_step span on the engine lane,
-    and the trace still validates (nesting is per-tid)."""
+    span on the 200+ lane overlaps a serve.dispatch span (the step's
+    enqueue) on the engine lane, and the trace still validates (nesting
+    is per-tid)."""
     cfg, api, params = qwen
     obs = Obs(trace=True)
     eng = ServingEngine(api, params, max_batch=2, max_seq=64, page_tokens=8,
@@ -352,7 +353,7 @@ def test_promote_span_overlaps_serve_step(qwen):
     assert validate_chrome_trace(doc) == []
     evs = doc["traceEvents"]
     promotes = [e for e in evs if e["name"] == "promote"]
-    steps = [e for e in evs if e["name"] == "serve_step"]
+    steps = [e for e in evs if e["name"] == "serve.dispatch"]
     assert promotes and steps
     assert all(e["tid"] >= 200 for e in promotes)
     def span(e):
@@ -361,7 +362,7 @@ def test_promote_span_overlaps_serve_step(qwen):
     assert overlapped, "no promotion landed while the engine was stepping"
     p0, p1 = span(overlapped[0])
     assert any(s0 < p1 and p0 < s1 for s0, s1 in map(span, steps)), \
-        "promote span does not overlap any serve_step span"
+        "promote span does not overlap any serve.dispatch span"
     assert json.dumps(doc)                   # serializable end to end
     demotes = [e for e in evs if e["name"] == "demote"]
     assert demotes and all(e["tid"] == 2 for e in demotes)

@@ -27,15 +27,20 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
 from repro.obs import validate_chrome_trace  # noqa: E402
 
-REQUIRED_SPANS = {"step", "admit", "schedule", "serve_step", "sample",
-                  # speculative decoding taxonomy: drafting (client-side
-                  # guesswork), the verify pass over the target logits,
-                  # and the metadata-only rollback of rejected tails
-                  "draft", "verify", "rollback",
-                  # host-tier taxonomy (DESIGN.md §8a): D2H spills on
-                  # tid 2, and [enqueue -> flip] promotion spans on the
-                  # per-slot 200+ lanes (overlapping serve_step by design)
-                  "demote", "promote"}
+REQUIRED_SPANS = {
+    # the engine step's phases (DESIGN.md §10), one span each, nested in
+    # serve.step; serve.queued is each request's submit -> slot wait
+    "serve.step", "serve.admit", "serve.stage", "serve.sync_page_table",
+    "serve.dispatch", "serve.device_wait", "serve.logits_to_host",
+    "serve.sample", "serve.queued",
+    # speculative decoding taxonomy: drafting (client-side guesswork),
+    # the verify pass over the target logits, and the metadata-only
+    # rollback of rejected tails
+    "serve.draft", "serve.verify", "serve.rollback",
+    # host-tier taxonomy (DESIGN.md §8a): D2H spills on tid 2, the flip
+    # phase of the step, and [enqueue -> flip] promotion spans on the
+    # per-slot 200+ lanes (overlapping serve.dispatch by design)
+    "demote", "serve.promote_flip", "promote"}
 
 # cluster-plane taxonomy (DESIGN.md §12): route instants at submit, and
 # per-session snapshot spans nested inside each migrate span.  The
