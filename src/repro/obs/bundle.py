@@ -141,6 +141,12 @@ def attach_serving(obs: Obs, engine) -> None:
     reg.register("engine.cancels", lambda: engine.cancels, monotonic=True)
     reg.register("engine.backpressure_stalls",
                  lambda: engine.backpressure_stalls, monotonic=True)
+    # token selection: tokens taken from the device's argmax ids, and
+    # logits rows the host sampled for stochastic requests
+    reg.register("engine.device_selected_tokens",
+                 lambda: engine.device_selected_tokens, monotonic=True)
+    reg.register("engine.host_sampled_rows",
+                 lambda: engine.host_sampled_rows, monotonic=True)
     reg.register("engine.slots_active", lambda: len(engine.active))
     reg.register("engine.waiting", lambda: len(engine.waiting))
     reg.register("engine.slot_occupancy",

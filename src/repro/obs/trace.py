@@ -16,8 +16,10 @@ there too.  Spans nest on the engine's thread (DESIGN.md §10):
       serve.sync_page_table
       serve.dispatch       the uploads and the jitted step: the enqueue
       serve.promote_flip   staged host-tier promotions, when any
-      serve.device_wait    block_until_ready on the step's logits
-      serve.logits_to_host the logits copy to NumPy (args: bytes)
+      serve.device_wait    block_until_ready on the step's token ids
+      serve.logits_to_host the copy to NumPy of the ids, and of the last
+                           rows when a stochastic request samples
+                           (args: bytes copied, rows host-sampled)
       serve.sample         the post-step loop (serve.publish,
                            serve.verify, serve.rollback inside)
 

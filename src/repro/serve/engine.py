@@ -29,7 +29,11 @@ Pool geometry comes from ``api.kv_geometry`` — the same formula that sizes
 the pools — never from inspecting an initial page table (which under-sizes
 the pool when the table is sparse).
 
-Sampling is greedy or softmax on the host.
+Token ids are picked on the device: one jitted selector (``select_tokens``)
+takes the step's argmax over every position, and the host copies those
+ids back, ``[B, C]`` int32, never the ``[B, C, V]`` logits.  Softmax rows
+for stochastic requests are sampled on the host: only then does the
+step also copy each slot's last valid logits row.
 """
 
 from __future__ import annotations
@@ -37,9 +41,10 @@ from __future__ import annotations
 import itertools
 import time
 from dataclasses import dataclass, field, replace
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..core.kvcache import KVPoolFullError, PagedKVCache
@@ -64,7 +69,52 @@ class SamplingParams:
         if self.top_k < 0:
             raise ValueError("top_k must be >= 0")
 
+    @property
+    def greedy(self) -> bool:
+        """Argmax sampling: the token is the device's pick."""
+        return self.temperature <= 0.0 or self.top_k == 1
+
 GREEDY = SamplingParams()
+
+
+@jax.jit
+def select_tokens(logits: jax.Array, n_new: jax.Array
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """On the device, what the host sampler needs of a step's ``logits
+    [B, C, V]``: ``ids [B, C]``, the argmax of every position (int32;
+    ``jnp.argmax`` returns the first maximal index, the lowest-id tie rule
+    of ``ServingEngine._sample``), and ``last [B, V]``, each slot's row at
+    its last valid position ``max(n_new - 1, 0)``.  One module-level jit:
+    every engine in the process shares its one executable per width."""
+    ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    # a per-slot dynamic slice reads B rows (a take_along_axis here
+    # compiled to a copy of the whole logits for the v5e)
+    last = jax.vmap(lambda rows, p: jax.lax.dynamic_index_in_dim(
+        rows, p, 0, keepdims=False))(logits, jnp.maximum(n_new - 1, 0))
+    return ids, last
+
+
+class _Selected(NamedTuple):
+    """What the host holds of one step's logits (``select_tokens``)."""
+    ids: np.ndarray                 # [B, C] argmax ids
+    last: Optional[np.ndarray]      # [B, V] last valid rows, when copied
+    vocab: int
+
+
+class _Picked:
+    """A logits row of which the host holds only the device's argmax:
+    all the greedy branch of ``ServingEngine._sample`` reads of a row."""
+    __slots__ = ("token", "vocab")
+
+    def __init__(self, token: int, vocab: int) -> None:
+        self.token = token
+        self.vocab = vocab
+
+    def argmax(self) -> int:
+        return self.token
+
+    def __len__(self) -> int:
+        return self.vocab
 
 
 @dataclass(frozen=True)
@@ -235,6 +285,10 @@ class ServingEngine:
         self.spec_accepted_tokens = 0
         self.spec_rejected_tokens = 0
         self.spec_rollbacks = 0         # rollbacks that actually shrank
+        # token selection: tokens taken from the device's argmax ids, and
+        # logits rows copied to the host for a stochastic request
+        self.device_selected_tokens = 0
+        self.host_sampled_rows = 0
         self.draft_ns = 0               # host drafting time (client bucket)
         # tier promotion counters (lag = enqueue -> page-table flip; the
         # windowed profiler derives promote_lag_ms from the pair)
@@ -276,7 +330,7 @@ class ServingEngine:
         eff_spec = spec if spec is not None else self.default_spec
         if eff_spec is not None and (
                 self._recurrent                       # can't rewind state
-                or not (samp.temperature <= 0.0 or samp.top_k == 1)):
+                or not samp.greedy):
             eff_spec = None      # greedy-only (see SpecConfig docstring)
         req = Request(next(self._rid), list(prompt), max_new_tokens,
                       mode=self.controller.mode if mode is None else mode,
@@ -403,9 +457,13 @@ class ServingEngine:
             # the post loop, but the step's shared cost is still theirs
             part_reqs = [self.active[slot] for slot in feeds]
             with self._span("serve.dispatch") as dispatch:
+                tok_dev = self._upload(tokens)
+                n_new_dev = self._upload(n_new)
                 logits, self.caches = self._step_fn(
-                    self.params, self._upload(tokens), self.caches,
-                    self._upload(n_new))
+                    self.params, tok_dev, self.caches, n_new_dev)
+                vocab = logits.shape[-1]
+                ids, last = select_tokens(logits, n_new_dev)
+                del logits        # the full logits never leave the device
             # staged promotions land HERE — after the step's compute was
             # dispatched, against the post-step pool arrays (disjoint
             # pages), so the H2D copies ride the async queue concurrent
@@ -414,12 +472,17 @@ class ServingEngine:
             # reads the copied bytes
             self._flip_promotions(overlapped=True)
             with self._span("serve.device_wait") as wait:
-                jax.block_until_ready(logits)
-            with self._span("serve.logits_to_host", bytes=logits.nbytes):
-                logits = np.asarray(logits)
+                jax.block_until_ready(ids)
+            # the ids always come back; the last rows only when a
+            # stochastic request samples on the host this step
+            rows = self._host_rows(feeds, spec_feeds)
+            nbytes = ids.nbytes + (last.nbytes if rows else 0)
+            with self._span("serve.logits_to_host", bytes=nbytes, rows=rows):
+                sel = _Selected(np.asarray(ids),
+                                np.asarray(last) if rows else None, vocab)
             self.tokens_processed += int(sum(feeds.values()))
             with self._span("serve.sample"):
-                self._sample_feeds(feeds, spec_feeds, logits)
+                self._sample_feeds(feeds, spec_feeds, sel)
             self.steps += 1
         if self.obs is not None:
             self._account_step(self.obs, st, dispatch.ns + wait.ns, draft_ns,
@@ -543,9 +606,33 @@ class ServingEngine:
                                           publish=slot not in spec_feeds)
         return tokens, n_new, feeds, spec_feeds
 
+    def _host_rows(self, feeds: Dict[int, int],
+                   spec_feeds: Dict[int, List[int]]) -> int:
+        """Rows the host samples this step: one per stochastic request
+        whose feed ends its prompt or decodes (speculation is greedy)."""
+        rows = 0
+        for slot, take in feeds.items():
+            req = self.active[slot]
+            if slot not in spec_feeds and not req.sampling.greedy \
+                    and req.prompt_pos + take >= len(req.prompt):
+                rows += 1
+        return rows
+
+    def _token(self, req: Request, i: int, sel: _Selected) -> int:
+        """The token after feed position ``i`` of ``req``: the device's
+        argmax for a greedy request, else a host sample of its last valid
+        row (``i`` is then the feed's last position).  Either way it comes
+        out of ``_sample``."""
+        if req.sampling.greedy:
+            self.device_selected_tokens += 1
+            return self._sample(_Picked(int(sel.ids[req.slot, i]), sel.vocab),
+                                req.sampling)
+        self.host_sampled_rows += 1
+        return self._sample(sel.last[req.slot], req.sampling)
+
     def _sample_feeds(self, feeds: Dict[int, int],
                       spec_feeds: Dict[int, List[int]],
-                      logits: np.ndarray) -> None:
+                      sel: _Selected) -> None:
         """The post-step loop: advance cursors, publish finished prompts
         into the trie, sample (or verify drafts), and finish requests."""
         for slot, take in feeds.items():
@@ -564,16 +651,15 @@ class ServingEngine:
                             req.prompt,
                             self.controller.committed_extents(req.seq_id))
             if slot in spec_feeds:
-                # draft-and-verify epilogue: all take logits came back
+                # draft-and-verify epilogue: all take ids came back
                 # from ONE step; accept the longest agreeing prefix and
                 # roll back the rejected tail (metadata-only)
-                self._verify_spec(slot, req, take, spec_feeds[slot], logits)
+                self._verify_spec(req, take, spec_feeds[slot], sel)
             else:
                 # the chunk's last valid position predicts the next
                 # token: the final prefill chunk yields the first
                 # generated token for free
-                tok = self._sample(logits[slot, take - 1], req.sampling)
-                req.output.append(tok)
+                req.output.append(self._token(req, take - 1, sel))
             total = self.controller.seq_length(req.seq_id)
             if len(req.output) >= req.max_new_tokens:
                 self._finish(slot, req)
@@ -581,16 +667,16 @@ class ServingEngine:
                 req.truncated = True        # capacity-bound, not completed
                 self._finish(slot, req)
 
-    def _verify_spec(self, slot: int, req: Request, take: int,
-                     d: List[int], logits: np.ndarray) -> None:
+    def _verify_spec(self, req: Request, take: int, d: List[int],
+                     sel: _Selected) -> None:
         """Accept the longest draft prefix the target model agrees with.
 
         The step fed ``[output[-1]] + d`` (take = 1 + len(d) positions),
-        so position i's logits predict the token AFTER the i-th fed
-        token: sample each in turn, stop at the first disagreement —
-        every sampled token up to and including that position is a real
-        model output (the token after the last accepted draft comes free,
-        exactly like the final prefill chunk's bonus token).
+        so position i's argmax (speculation is greedy-only) is the token
+        AFTER the i-th fed token: take each in turn, stop at the first
+        disagreement — every token up to and including that position is a
+        real model output (the token after the last accepted draft comes
+        free, exactly like the final prefill chunk's bonus token).
 
         KV protocol (DESIGN.md §8): the append above STAGED all ``take``
         positions (no publish).  ``commit(upto_len=target)`` publishes
@@ -604,7 +690,7 @@ class ServingEngine:
                         drafted=len(d)) as sv:
             new_toks: List[int] = []
             for i in range(take):
-                tok = self._sample(logits[slot, i], req.sampling)
+                tok = self._token(req, i, sel)
                 new_toks.append(tok)
                 if i < take - 1 and d[i] != tok:
                     break
@@ -718,15 +804,17 @@ class ServingEngine:
 
     def _sample(self, row: np.ndarray, sp: SamplingParams = GREEDY) -> int:
         """The ONE host sampler: per-request temperature / top-k feed it
-        parameters, but every request's logits go through this path.
+        parameters, but every emitted token comes out of this path.
 
-        Tie-break contract: LOWEST token id wins every tie.  Greedy relies
-        on np.argmax returning the first maximal index; top-k truncation
+        Tie-break contract: LOWEST token id wins every tie.  Greedy reads
+        only ``row.argmax()``, the first maximal index: the engine hands
+        it a ``_Picked`` row whose argmax ``select_tokens`` took on the
+        device by the same rule (a NumPy row works alike); top-k truncation
         uses a stable descending sort so a tie straddling the k-th place
         keeps exactly k candidates (the lowest-id ones) rather than
         admitting every tied logit (the old partition-threshold behavior,
         which made verify-vs-draft agreement depend on memory order)."""
-        if sp.temperature <= 0.0 or sp.top_k == 1:
+        if sp.greedy:
             return int(row.argmax())     # first (lowest-id) maximal entry
         z = row.astype(np.float64) / sp.temperature
         if sp.top_k and sp.top_k < len(row):

@@ -243,7 +243,8 @@ def _profiled(tmp_path, fn):
 def _serve_two(client):
     sess = client.open_session()
     sess.submit(list(range(1, 20)), max_new_tokens=3)
-    sess.submit(list(range(1, 12)), max_new_tokens=2)
+    sess.submit(list(range(1, 12)), max_new_tokens=2, temperature=0.8,
+                top_k=8)
     client.run_until_done()
 
 
@@ -288,9 +289,15 @@ def test_step_phases_are_profiler_spans_and_ring_entries(qwen, tmp_path):
              if n == "serve.step" and "width" in a}
     copies = [a for n, _, _, a in ring if n == "serve.logits_to_host"]
     assert len(copies) == len(width) == computed
+    # int32 ids of every position, plus each slot's last logits row on
+    # the steps where the stochastic request samples on the host
     for a in copies:
-        per = a["bytes"] // (B * width[a["step"]] * V)
-        assert per in (2, 4) and a["bytes"] == per * B * width[a["step"]] * V
+        ids = B * width[a["step"]] * 4
+        if a["rows"]:
+            assert a["bytes"] - ids in (B * V * 2, B * V * 4)
+        else:
+            assert a["bytes"] == ids
+    assert {a["rows"] for a in copies} == {0, 1}
     queued = [a["rid"] for n, _, t1, a in program_tracer().spans()
               if n == "serve.queued" and lo <= t1 <= hi]
     assert sorted(queued) == [0, 1]
