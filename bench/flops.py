@@ -3,14 +3,15 @@ sizes and the live lengths of each call.  Never from the program's padded
 shapes, page tables or grids: the yardstick stays the same whatever
 implements the step.
 
-A configuration file gives the published key names (``hidden_size``,
-``num_attention_heads``, ...); ``Dims`` reads them once.
+``Dims`` holds the sizes of a dense GQA model; the family module
+``bench/families/dense_gqa.py`` reads them from a configuration file's
+published keys.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, Iterable, Optional, Tuple
+from typing import Iterable, Optional, Tuple
 
 BF16_BYTES = 2
 
@@ -26,18 +27,6 @@ class Dims:
     vocab: int
     gated_mlp: bool            # SwiGLU: gate, up and down; else up and down
     window: Optional[int]      # sliding window in keys, None for full
-
-    @staticmethod
-    def of(cfg: Dict) -> "Dims":
-        heads = int(cfg["num_attention_heads"])
-        d = int(cfg["hidden_size"])
-        return Dims(
-            layers=int(cfg["num_hidden_layers"]), d_model=d, heads=heads,
-            kv_heads=int(cfg["num_key_value_heads"]),
-            head_dim=int(cfg.get("head_dim") or d // heads),
-            d_ff=int(cfg["intermediate_size"]), vocab=int(cfg["vocab_size"]),
-            gated_mlp=cfg["hidden_act"] == "silu",
-            window=cfg.get("sliding_window"))
 
 
 def keys_seen(d: Dims, pos: int) -> int:
@@ -94,6 +83,15 @@ def paged_attention_cost(d: Dims, fed: Iterable[Tuple[int, int]]
         byts += 2 * kv_tokens * d.kv_heads * d.head_dim * BF16_BYTES
         byts += 2 * n * d.heads * d.head_dim * BF16_BYTES
     return flops * d.layers, byts * d.layers
+
+
+def kv_append_cost(d: Dims, fed: Iterable[Tuple[int, int]]
+                   ) -> Tuple[int, int]:
+    """(FLOPs, bytes) of one step's KV-append calls, one for K and one for
+    V per layer: each reads the rows of the ``n`` tokens a slot feeds and
+    writes them into the pool.  No arithmetic."""
+    tokens = sum(n for n, _ in fed)
+    return 0, d.layers * 2 * 2 * tokens * d.kv_heads * d.head_dim * BF16_BYTES
 
 
 def roofline_seconds(flops: float, byts: float, peak_flops: float,

@@ -10,6 +10,14 @@ limits or a per-layer metric is a file found by the name
   limits          bench/limits/<cell>.json
   per-layer read  bench/metrics/<metric>.py, a ``read(run)`` function
   reference       bench/reference/<config's "reference">.py
+  family          bench/families/<config's "reference">.py: the served
+                  sizes, the FLOP and byte counts and the traced kernels
+
+A configuration file states only keys that something accounts for: its
+family reads them or names them as left to other code, the file lists
+them under ``reduced`` or ``assumed``, or they are the file's own
+bookkeeping (``BOOKKEEPING``).  Any other key stops the run before
+anything is built, so no published size drops out of the served model.
 """
 
 from __future__ import annotations
@@ -23,12 +31,14 @@ import shutil
 import sys
 import time
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
 TRACE_DIR = ROOT / "chiprun_out" / "bench_trace"
 OPLOG_BLOCKS = 1024          # 64 K page publishes: far more than a run makes
+BOOKKEEPING = frozenset({"name", "source", "paper", "deployment", "serving",
+                         "reference", "reduced", "assumed"})
 
 
 def log(msg: str) -> None:
@@ -50,6 +60,7 @@ class Cell:
     end_to_end: List[Dict]
     per_layer: List[Dict]
     peaks: Dict
+    family: Any                  # the config's module under bench/families
 
 
 def load_cell(name: str, root: Path = ROOT) -> Cell:
@@ -65,13 +76,14 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     per_layer = [m for m in bench["per_layer"]
                  if (name in m["workloads"] if "workloads" in m
                      else m["moves"] in moves)]
-    return Cell(name=name, entry=entry,
-                config=load_json(root / cfg_entry["file"]),
+    config = load_json(root / cfg_entry["file"])
+    return Cell(name=name, entry=entry, config=config,
                 traffic=load_json(root / "bench" / "traffic"
                                   / f"{entry['traffic']}.json"),
                 limits=load_json(root / "bench" / "limits" / f"{name}.json"),
                 end_to_end=e2e, per_layer=per_layer,
-                peaks=load_json(root / "bench" / "peaks.json"))
+                peaks=load_json(root / "bench" / "peaks.json"),
+                family=load_family(config, root))
 
 
 def load_module(path: Path):
@@ -80,6 +92,22 @@ def load_module(path: Path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_family(cfg: Dict, root: Path = ROOT):
+    """The family module a configuration file's ``reference`` names.
+    Raises SystemExit naming every key of the file that nothing accounts
+    for."""
+    path = root / "bench" / "families" / f"{cfg['reference']}.py"
+    family = load_module(path)
+    known = (BOOKKEEPING | set(family.READS) | set(family.LEAVES)
+             | set(cfg.get("reduced", ())) | set(cfg.get("assumed", ())))
+    stray = sorted(k for k in cfg if k not in known)
+    if stray:
+        raise SystemExit(f"bench: {cfg.get('name')}: {', '.join(stray)} "
+                         f"neither read nor left by {path.name}, nor "
+                         f"reduced or assumed in the file")
+    return family
 
 
 # ---------------------------------------------------------------- device
@@ -110,27 +138,16 @@ def device_peaks(cell: Cell, chips: int):
 # ---------------------------------------------------------------- model
 
 
-def program_config(cfg: Dict):
+def program_config(cfg: Dict, family):
     """The program's ModelConfig for a configuration file: the repository's
-    config of the same model, with every size the file states."""
+    config of the same model, with every field the family reads from the
+    file, in the type ``serving.param_dtype`` names."""
     import jax.numpy as jnp
     from repro.configs import get_config
 
     serving = cfg["serving"]
-    heads = int(cfg["num_attention_heads"])
     return dataclasses.replace(
-        get_config(serving["repo_config"]),
-        n_layers=int(cfg["num_hidden_layers"]),
-        d_model=int(cfg["hidden_size"]), n_heads=heads,
-        n_kv_heads=int(cfg["num_key_value_heads"]),
-        head_dim=int(cfg.get("head_dim") or cfg["hidden_size"] // heads),
-        d_ff=int(cfg["intermediate_size"]), vocab=int(cfg["vocab_size"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        rope_theta=float(cfg["rope_theta"]),
-        attn_window=cfg.get("sliding_window"),
-        norm="rmsnorm" if "rms_norm_eps" in cfg else "layernorm",
-        mlp="swiglu" if cfg["hidden_act"] == "silu" else "gelu",
-        qkv_bias=bool(cfg.get("attention_bias", cfg.get("use_bias"))),
+        get_config(serving["repo_config"]), **family.program_fields(cfg),
         param_dtype=getattr(jnp, serving["param_dtype"]))
 
 
@@ -172,13 +189,13 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     from repro.models.spec import abstract_params
     from repro.serve import ServeClient
 
-    from . import correctness, driver, e2e, flops, tracing, traffic, weights
+    from . import correctness, driver, e2e, tracing, traffic, weights
 
     log(f"compile cache: {enable_compile_cache()}")
     driver.CompileCounter.get()
     cfg, mix = cell.config, cell.traffic
     serving = cfg["serving"]
-    pc = program_config(cfg)
+    pc = program_config(cfg, cell.family)
     api = build_model(pc)
     params = weights.make(abstract_params(api.init_specs()), seed,
                           pc.d_model, pc.tie_embeddings)
@@ -252,12 +269,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     del drv, sessions, engine, client, ctrl
     gc.collect()
 
-    dims = flops.Dims.of(cfg)
-    rec.cell = {"dims": dims, "peaks": peaks or {}}
+    rec.cell = {"family": cell.family, "config": cfg, "peaks": peaks or {}}
     if trace:
-        kernels = ["paged_attention_chunk", "kv_append_chunk"]
         rec.trace = tracing.summarize(tracing.find_xplane(str(trace_dir)),
-                                      kernels)
+                                      list(cell.family.KERNEL_COSTS))
         shutil.rmtree(trace_dir, ignore_errors=True)
 
     # metrics

@@ -46,29 +46,32 @@ def prefix_hit_share(run) -> Optional[float]:
 
 
 def mfu(run) -> Optional[float]:
-    """Model FLOPs of the valid tokens the window's steps processed, over
-    window seconds times the chip's bf16 peak, as a percentage."""
+    """Model FLOPs of the valid tokens the window's steps processed, as
+    the family counts them, over window seconds times the chip's bf16
+    peak, as a percentage."""
     # the trace's window is the host span bench.window: the driver's
     # window on the profiler's clock, so the window's steps are traced
     tr, steps = run.trace, window_steps(run)
     if tr is None or not steps or not tr.devices:
         return None
-    dims, peaks = run.cell["dims"], run.cell["peaks"]
-    total = sum(flops.model_flops(dims, s.fed) for s in steps)
+    family, cfg, peaks = (run.cell[k] for k in ("family", "config", "peaks"))
+    total = sum(family.model_flops(cfg, s.fed) for s in steps)
     return 100.0 * total / (tr.window_s * peaks["bf16_flops"] * tr.devices)
 
 
 def roofline(run, kernel: str) -> Optional[float]:
     """The least time the kernel's calls need (the larger of FLOPs over
-    peak and bytes over HBM bandwidth, from each call's live lengths) over
-    the summed device time of its trace events, as a percentage."""
+    peak and bytes over HBM bandwidth, from each call's live lengths, as
+    the family counts them) over the summed device time of its trace
+    events, as a percentage."""
     tr, steps = run.trace, window_steps(run)
-    if tr is None or not steps or not tr.kernel_ns.get(kernel):
+    cost = run.cell["family"].KERNEL_COSTS.get(kernel)
+    if cost is None or tr is None or not steps or not tr.kernel_ns.get(kernel):
         return None
-    dims, peaks = run.cell["dims"], run.cell["peaks"]
+    cfg, peaks = run.cell["config"], run.cell["peaks"]
     f = b = 0
     for s in steps:
-        sf, sb = flops.paged_attention_cost(dims, s.fed)
+        sf, sb = cost(cfg, s.fed)
         f, b = f + sf, b + sb
     least, _ = flops.roofline_seconds(f, b, peaks["bf16_flops"],
                                       peaks["hbm_bytes_per_s"])

@@ -10,9 +10,11 @@ tokens against the plain reference, and prints one JSON line last on
 standard output: the end-to-end metrics with ``--trace 0``, the per-layer
 metrics (from a profiler trace of the window) with ``--trace 1``.
 
-It exits non-zero, printing no result, where JAX finds no TPU, fewer chips
-than the cell asks for, a device kind missing from bench/peaks.json, or
-kernels that would not be the Pallas ones.
+It exits non-zero, printing no result, where the configuration file
+states a key that nothing accounts for (its family module under
+bench/families/, its ``reduced`` or ``assumed``), or JAX finds no TPU,
+fewer chips than the cell asks for, a device kind missing from
+bench/peaks.json, or kernels that would not be the Pallas ones.
 
 ``--control 1`` runs the control instead of the check: the float8
 reference's first tokens are scored in place of the served ones, so a

@@ -91,4 +91,5 @@ def rehearsal_cell(mix: str) -> harness.Cell:
         config=dict(QWEN2), traffic=MIXES[mix],
         limits={"max_logit_gap": 0.4}, end_to_end=REHEARSAL_E2E[mix],
         per_layer=[],
-        peaks=harness.load_json(root / "bench" / "peaks.json")))
+        peaks=harness.load_json(root / "bench" / "peaks.json"),
+        family=harness.load_family(QWEN2)))

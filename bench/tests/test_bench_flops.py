@@ -6,6 +6,7 @@ from pathlib import Path
 import pytest
 
 from bench import flops
+from bench.families import dense_gqa
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 # Qwen2-1.5B's published sizes (arXiv:2407.10671): a gated MLP, full
@@ -18,8 +19,8 @@ QWEN2_1_5B = {"hidden_size": 1536, "intermediate_size": 8960,
 
 def dims(name):
     if name == "qwen2-1.5b":
-        return flops.Dims.of(QWEN2_1_5B)
-    return flops.Dims.of(json.loads((CONFIGS / f"{name}.json").read_text()))
+        return dense_gqa.dims(QWEN2_1_5B)
+    return dense_gqa.dims(json.loads((CONFIGS / f"{name}.json").read_text()))
 
 
 def test_qwen2_linear_flops_by_hand():
@@ -59,6 +60,14 @@ def test_window_bounds_the_keys():
     assert f == 16 * 4 * 36 * 128 * (4096 + 4096)
     # keys from 4200 + 1 - 4096 = 105 to 4201
     assert b == 16 * (2 * (4202 - 105) * 4 * 128 * 2 + 2 * 2 * 36 * 128 * 2)
+
+
+def test_kv_append_reads_and_writes_the_fed_rows():
+    d = dims("starcoder2-7b.pp2")
+    # 128 + 1 tokens, K and V, read once and written once, 4 heads x 128
+    assert flops.kv_append_cost(d, [(128, 0), (1, 5000)]) == \
+        (0, 16 * 2 * 2 * 129 * 4 * 128 * 2)
+    assert flops.kv_append_cost(d, []) == (0, 0)
 
 
 def test_decode_batch_sums_slots():

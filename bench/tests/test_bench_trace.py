@@ -11,7 +11,13 @@ from types import SimpleNamespace
 import pytest
 
 from bench import flops, layer_stats, tracing
+from bench.families import dense_gqa
 
+# the trace's model: Qwen2-1.5B's widths, 2 layers
+QWEN2_2_LAYERS = {"hidden_size": 1536, "intermediate_size": 8960,
+                  "num_attention_heads": 12, "num_key_value_heads": 2,
+                  "num_hidden_layers": 2, "vocab_size": 151936,
+                  "hidden_act": "silu"}
 TRACE = Path(__file__).resolve().parents[1] / "testdata" / \
     "small_window.xplane.pb"
 
@@ -62,12 +68,13 @@ def test_idle_share_and_roofline_readers(summary):
         trace=summary, window=(0.0, 1.0),
         steps=[SimpleNamespace(t0=0.1, t1=0.2, prefill=False,
                                fed=[(1, 300)] * 4)],
-        cell={"dims": flops.Dims(2, 1536, 12, 2, 128, 8960, 151936, True,
-                                 None),
+        cell={"family": dense_gqa, "config": QWEN2_2_LAYERS,
               "peaks": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}})
     assert layer_stats.idle_share(run) == pytest.approx(
         100 * (1 - 0.032639658 / 1.020983571))
-    f, b = flops.paged_attention_cost(run.cell["dims"], [(1, 300)] * 4)
+    f, b = flops.paged_attention_cost(
+        flops.Dims(2, 1536, 12, 2, 128, 8960, 151936, True, None),
+        [(1, 300)] * 4)
     least = max(f / 197e12, b / 819e9)
     assert layer_stats.roofline(run, "paged_attention_chunk") == \
         pytest.approx(100 * least / 462301e-9)

@@ -7,7 +7,9 @@ paths, shapes and dtypes); the values are the benchmark's own:
   * matrices   normal, std 1 / sqrt(fan_in), so each projection keeps the
                scale of its input;
   * biases     normal, std 0.1, so the bias paths change the result;
-  * norm gains 1 + normal(0.1); norm biases normal(0.1);
+  * norm gains 1 + normal(0.1); norm biases normal(0.1): the leaves of
+    any parent whose name holds ``norm`` (block norms, the final norm, a
+    latent or per-head q/k norm);
   * the embedding, where it is also the unembedding (tied), normal with
     std LOGIT_STD / sqrt(d_model): logits over a normalised hidden state
     then have a standard deviation near LOGIT_STD, at any width;
@@ -42,7 +44,7 @@ def _leaf(key, names: Tuple[str, ...], shape, dtype, d_model: int,
           tied: bool):
     name, parent = names[-1], names[-2] if len(names) > 1 else ""
     normal = lambda std: jax.random.normal(key, shape, jnp.float32) * std
-    if parent.startswith("norm") or parent == "final_norm":
+    if "norm" in parent:
         x = 1.0 + normal(0.1) if name == "w" else normal(0.1)
     elif name == "embed":
         x = normal(LOGIT_STD / math.sqrt(d_model) if tied else 1.0)
